@@ -1,25 +1,48 @@
 """Serving driver: SLIMSTART-instrumented serverless model server.
 
-Simulates the paper's full CI/CD loop on a real (reduced) model:
+Simulates the paper's full CI/CD loop on a model at its published
+widths (``--reduced`` for the small same-family CPU rehearsal config):
   1. cold start under a policy (eager | lazy | slimstart),
   2. serve a skewed multi-entry workload (the paper's Fig. 3 shape),
   3. emit the SLIMSTART report; --optimize re-derives the policy from
      the profile and re-measures the cold start (the Level-B analogue of
      the AST deferred-import rewrite).
 
-    PYTHONPATH=src python -m repro.launch.serve --arch whisper-large-v3 \
+    PYTHONPATH=src python -m repro.launch.serve --arch granite-moe-1b-a400m \
         --requests 20 --policy slimstart
+
+JAX's persistent compilation cache lives in ``$JAX_COMPILATION_CACHE_DIR``
+where that is set, and otherwise in ``<checkout>/.jax_cache``.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
+import os
+from pathlib import Path
 
+import jax
 import numpy as np
 
-from repro.configs import get_reduced
+from repro.configs import get_config, get_reduced
 from repro.serving import LoadPolicy, ServingEngine
+
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, where set, places it (JAX reads the
+    variable itself).  Otherwise it goes to the fixed ``<checkout>/
+    .jax_cache``: the directory is part of what a later run must find,
+    so it never carries a temp name, pid or time."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def skewed_workload(entries, n, seed=0, alpha=0.85):
@@ -53,9 +76,13 @@ def main():
     ap.add_argument("--policy", default="slimstart",
                     choices=["eager", "lazy", "slimstart"])
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the small same-family config (CPU "
+                         "rehearsal) instead of the published widths")
     args = ap.parse_args()
 
-    cfg = get_reduced(args.arch)
+    enable_compile_cache()
+    cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     probe = ServingEngine(cfg, batch_size=1)
     entries = probe.entries()
     workload = skewed_workload(entries, args.requests, seed=args.seed)

@@ -39,14 +39,21 @@ class ParamSpec:
     init: str = "normal"  # "normal" | "zeros" | "ones"
     scale: Optional[float] = None  # None => 1/sqrt(fan_in)
 
+    def std(self) -> float:
+        """Standard deviation of a "normal" init: ``scale``, else
+        1/sqrt(fan_in) with the fan-in on the leading axis."""
+        if self.scale is not None:
+            return self.scale
+        fan_in = self.shape[0] if len(self.shape) > 1 else self.shape[-1]
+        return 1.0 / math.sqrt(max(fan_in, 1))
+
     def initializer(self, key, dtype):
         if self.init == "zeros":
             return jnp.zeros(self.shape, dtype)
         if self.init == "ones":
             return jnp.ones(self.shape, dtype)
-        fan_in = self.shape[0] if len(self.shape) > 1 else self.shape[-1]
-        scale = self.scale if self.scale is not None else 1.0 / math.sqrt(max(fan_in, 1))
-        return (jax.random.normal(key, self.shape, f32) * scale).astype(dtype)
+        return (jax.random.normal(key, self.shape, f32) * self.std()
+                ).astype(dtype)
 
 
 # --------------------------------------------------------------------------
@@ -457,10 +464,12 @@ def moe_template(cfg: ArchConfig):
     e = cfg.moe
     return {
         "router": ParamSpec((D, e.n_experts), ("embed", None)),
+        # the leading expert axis is not the fan-in
         "wi": ParamSpec((e.n_experts, D, 2 * e.d_expert_ff),
-                        ("experts", "embed", "ff")),
+                        ("experts", "embed", "ff"), scale=D ** -0.5),
         "wo": ParamSpec((e.n_experts, e.d_expert_ff, D),
-                        ("experts", "ff", "embed")),
+                        ("experts", "ff", "embed"),
+                        scale=e.d_expert_ff ** -0.5),
     }
 
 

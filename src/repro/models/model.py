@@ -100,9 +100,11 @@ def block_template(cfg: ArchConfig, kind: str, *, encoder=False):
 
 
 def _stack_specs(tmpl, n):
+    # the init scale is pinned from the unstacked shape: the new leading
+    # "layers" axis is not the fan-in
     return jax.tree.map(
         lambda s: ParamSpec((n,) + s.shape, ("layers",) + s.axes, s.init,
-                            s.scale), tmpl,
+                            s.std()), tmpl,
         is_leaf=lambda x: isinstance(x, ParamSpec))
 
 

@@ -30,10 +30,44 @@ import numpy as np
 
 from repro.models.config import ArchConfig
 from repro.models.model import (
-    decode_step, init_cache, init_params, model_template, prefill,
+    _head, decode_step, forward, init_cache, init_params, prefill,
 )
 from repro.obs.tracing import get_tracer
 from repro.serving.components import Component, ComponentRegistry, LoadPolicy
+
+
+# step programs behind the entry points (jitted per engine with the
+# leading arguments bound, see ServingEngine.entry_programs)
+def score_step(cfg: ArchConfig, params, tokens):
+    """Teacher-forced logits (B, S, V) for every position."""
+    h, _, _ = forward(cfg, params, tokens)
+    return _head(cfg, params, h)
+
+
+def prefill_step(cfg: ArchConfig, cache_len: int, params, tokens, extra):
+    logits, caches, aux = prefill(cfg, params, tokens, cache_len=cache_len,
+                                  **extra)
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    load = aux.get("expert_load") if cfg.moe else None
+    return nxt, caches, load
+
+
+def decode_next(cfg: ArchConfig, params, token, pos, caches):
+    logits, caches = decode_step(cfg, params, token, pos, caches)
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return nxt[:, None], caches
+
+
+def _moe_groups(layers) -> list[dict]:
+    """The MoE parameter dicts of a layer tree, in a stable order (one
+    per stacked layer group)."""
+    out = []
+    for k, v in sorted(layers.items()):
+        if k == "moe":
+            out.append(v)
+        elif isinstance(v, dict):
+            out.extend(_moe_groups(v))
+    return out
 
 
 def _m_engine_dispatch(model: str, path: str) -> None:
@@ -109,40 +143,25 @@ class ServingEngine:
 
     # ---------------------------------------------------------- experts
     def _blank_experts(self, params):
-        def blank(leaf_path_ok):
-            return leaf_path_ok
-
-        def visit(tree):
-            for k, v in tree.items():
-                if k == "moe":
-                    v["wi"] = jnp.zeros_like(v["wi"])
-                    v["wo"] = jnp.zeros_like(v["wo"])
-                elif isinstance(v, dict):
-                    visit(v)
-        visit(params["layers"])
+        for moe in _moe_groups(params["layers"]):
+            moe["wi"] = jnp.zeros_like(moe["wi"])
+            moe["wo"] = jnp.zeros_like(moe["wo"])
         return params
 
     def _expert_builder(self, e: int):
         """Materialize expert e's FF weights in every MoE layer and patch
-        them into the live param tree."""
-        cfg = self.cfg
+        them into the live param tree.  Keys fold in only stable integer
+        indices (expert, layer group, weight), so every process builds
+        the same weights."""
         key = jax.random.fold_in(jax.random.PRNGKey(self.seed), 1000 + e)
-        params = self._params
-
-        def visit(tree, path=""):
-            for k, v in sorted(tree.items()):
-                if k == "moe":
-                    for w in ("wi", "wo"):
-                        shape = v[w].shape  # (n_stack, E, ...)
-                        sub = jax.random.normal(
-                            jax.random.fold_in(key, hash((path, w)) %
-                                               (2**31)),
-                            shape[:1] + shape[2:], jnp.float32)
-                        sub = (sub / np.sqrt(shape[2])).astype(v[w].dtype)
-                        v[w] = v[w].at[:, e].set(sub)
-                elif isinstance(v, dict):
-                    visit(v, path + "/" + k)
-        visit(params["layers"])
+        for g, moe in enumerate(_moe_groups(self._params["layers"])):
+            for i, w in enumerate(("wi", "wo")):
+                shape = moe[w].shape  # (n_stack, E, ...)
+                k = jax.random.fold_in(jax.random.fold_in(key, g), i)
+                sub = jax.random.normal(k, shape[:1] + shape[2:],
+                                        jnp.float32)
+                sub = (sub / np.sqrt(shape[2])).astype(moe[w].dtype)
+                moe[w] = moe[w].at[:, e].set(sub)
         return e
 
     # ------------------------------------------------------ compilation
@@ -159,43 +178,32 @@ class ServingEngine:
                 (B, cfg.encoder_seq, cfg.d_model), cfg.jdtype)
         return toks, extras
 
-    def _compile_entry(self, entry: str):
+    def entry_programs(self, entry: str) -> dict[str, tuple[Callable,
+                                                            tuple]]:
+        """The jitted step programs that serve ``entry``, each with the
+        abstract arguments it is compiled for (shapes only, no
+        sharding: the default device places them)."""
         cfg = self.cfg
         toks, extras = self._entry_shapes(entry)
-        cache_len = self.max_len + (cfg.vision_tokens or 0)
-
+        params = self._param_shapes()
         if entry == "score":
-            def score_fn(params, tokens):
-                from repro.models.model import forward, _head
-                h, _, _ = forward(cfg, params, tokens)
-                return _head(cfg, params, h)
-            compiled = jax.jit(score_fn).lower(
-                self._param_shapes(), toks).compile()
-            return {"score": compiled}
+            return {"score": (jax.jit(partial(score_step, cfg)),
+                              (params, toks))}
+        cache_len = self.max_len + (cfg.vision_tokens or 0)
+        caches = jax.eval_shape(lambda: init_cache(cfg, self.B, cache_len))
+        return {
+            "prefill": (jax.jit(partial(prefill_step, cfg, cache_len)),
+                        (params, toks, extras)),
+            "decode": (jax.jit(partial(decode_next, cfg)),
+                       (params,
+                        jax.ShapeDtypeStruct((self.B, 1), jnp.int32),
+                        jax.ShapeDtypeStruct((self.B,), jnp.int32),
+                        caches)),
+        }
 
-        def prefill_fn(params, tokens, extra):
-            logits, caches, aux = prefill(cfg, params, tokens,
-                                          cache_len=cache_len, **extra)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            load = aux.get("expert_load") if cfg.moe else None
-            return nxt, caches, load
-
-        def decode_fn(params, token, pos, caches):
-            logits, caches = decode_step(cfg, params, token, pos, caches)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return nxt[:, None], caches
-
-        extra_shapes = {k: v for k, v in extras.items()}
-        pre_c = jax.jit(prefill_fn).lower(
-            self._param_shapes(), toks, extra_shapes).compile()
-        cache_shapes = jax.eval_shape(
-            lambda: init_cache(cfg, self.B, cache_len))
-        dec_c = jax.jit(decode_fn).lower(
-            self._param_shapes(),
-            jax.ShapeDtypeStruct((self.B, 1), jnp.int32),
-            jax.ShapeDtypeStruct((self.B,), jnp.int32),
-            cache_shapes).compile()
-        return {"prefill": pre_c, "decode": dec_c}
+    def _compile_entry(self, entry: str):
+        return {name: fn.lower(*args).compile()
+                for name, (fn, args) in self.entry_programs(entry).items()}
 
     def _param_shapes(self):
         return jax.eval_shape(

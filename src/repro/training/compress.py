@@ -80,7 +80,6 @@ def shard_map_int8_allreduce(grads, mesh, axis: str = "pod"):
     max scale — a conservative shared-scale scheme.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     if axis not in mesh.shape:
         return grads
@@ -96,7 +95,7 @@ def shard_map_int8_allreduce(grads, mesh, axis: str = "pod"):
                          ).astype(jnp.int32)
             qs = jax.lax.psum(q, axis)
             return (qs.astype(f32) * scale_max / npods).astype(gl.dtype)
-        return shard_map(inner, mesh=mesh, in_specs=P(),
-                         out_specs=P(), check_vma=False)(g)
+        return jax.shard_map(inner, mesh=mesh, in_specs=P(),
+                             out_specs=P(), check_vma=False)(g)
 
     return jax.tree.map(reduce_one, grads)

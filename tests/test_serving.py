@@ -62,6 +62,56 @@ def test_moe_lazy_experts_materialize_on_route(moe_engine):
         assert eng.registry[f"expert.{e}"].ready
 
 
+def test_expert_weights_equal_across_hash_seeds():
+    """Expert weights depend on the engine seed only: two processes with
+    different ``PYTHONHASHSEED`` build the same bytes."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = Path(__file__).resolve().parents[1] / "src"
+    code = (
+        "import hashlib, numpy as np\n"
+        "from repro.configs import get_reduced\n"
+        "from repro.serving import LoadPolicy, ServingEngine\n"
+        "from repro.serving.engine import _moe_groups\n"
+        "eng = ServingEngine(get_reduced('granite-moe-1b-a400m'),\n"
+        "    policy=LoadPolicy(lazy_groups=frozenset({'compile'})))\n"
+        "eng.cold_start()\n"
+        "h = hashlib.sha256()\n"
+        "for moe in _moe_groups(eng._params['layers']):\n"
+        "    for w in ('wi', 'wo'):\n"
+        "        a = np.asarray(moe[w])\n"
+        "        assert (np.abs(a).sum(axis=tuple(range(2, a.ndim))) > 0).all()\n"
+        "        h.update(a.tobytes())\n"
+        "print(h.hexdigest())\n")
+    digests = [
+        subprocess.run(
+            [sys.executable, "-c", code], capture_output=True, text=True,
+            timeout=120, check=True,
+            env=dict(os.environ, PYTHONPATH=str(src), JAX_PLATFORMS="cpu",
+                     PYTHONHASHSEED=seed)).stdout.strip()
+        for seed in ("1", "2")]
+    assert digests[0] and digests[0] == digests[1]
+
+
+def test_params_init_at_one_layers_fan_in():
+    """Stacked layer weights and expert weights draw at 1/sqrt(fan_in)
+    of one layer's (one expert's) matrix, not of the stack or the expert
+    count: random weights then keep activations at unit scale."""
+    import jax
+    from repro.models.model import init_params
+
+    cfg = get_reduced("granite-moe-1b-a400m")
+    p = init_params(cfg, jax.random.PRNGKey(0))["layers"]["scan"]["pos0"]
+    for w, fan_in in [(p["attn"]["wq"], cfg.d_model),
+                      (p["attn"]["wo"], cfg.n_heads * cfg.head_dim),
+                      (p["moe"]["wi"], cfg.d_model),
+                      (p["moe"]["wo"], cfg.moe.d_expert_ff)]:
+        assert abs(float(np.std(np.asarray(w))) * fan_in ** 0.5 - 1) < 0.1
+
+
 def test_report_feeds_policy(moe_engine):
     rep = moe_engine.report()
     pol = LoadPolicy.from_report(rep)
