@@ -244,81 +244,87 @@ def _apply_block(p, cfg, kind, x, positions, *, cache=None, decode=False,
     aux = {}
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     new_cache = dict(cache) if cache is not None else None
-    if kind.startswith("attn"):
-        if decode:
-            kv_keys = [k for k in cache
-                       if not k.startswith("cross")]
-            y, kv = L.attn_decode(p["attn"], cfg, h, positions,
-                                  {k: cache[k] for k in kv_keys},
-                                  kind=kind)
-            new_cache.update(kv)
-        else:
-            y, kv = L.attn_apply(p["attn"], cfg, h, positions, kind=kind,
-                                 make_cache=make_cache)
-            if make_cache:
-                new_cache = kv
-    elif kind == "rglru":
-        if decode:
-            y, st = L.rglru_decode(p["rglru"], cfg, h,
-                                   {k: cache[k] for k in ("h", "conv")})
-            new_cache.update(st)
-        else:
-            y, st = L.rglru_apply(p["rglru"], cfg, h,
-                                  make_cache=bool(make_cache))
-            if make_cache:
-                new_cache = st
-    elif kind == "mlstm":
-        if decode:
-            y, st = L.mlstm_decode(p["mlstm"], cfg, h,
-                                   {k: cache[k] for k in ("C", "n", "m")})
-            new_cache.update(st)
-        else:
-            y, st = L.mlstm_apply(p["mlstm"], cfg, h,
-                                  make_cache=bool(make_cache))
-            if make_cache:
-                new_cache = st
-    elif kind == "slstm":
-        if decode:
-            y, st = L.slstm_decode(p["slstm"], cfg, h,
-                                   {k: cache[k]
-                                    for k in ("c", "n", "h", "m")})
-            new_cache.update(st)
-        else:
-            y, st = L.slstm_apply(p["slstm"], cfg, h,
-                                  make_cache=bool(make_cache))
-            if make_cache:
-                new_cache = st
+    # named scopes tag the device ops (``attn``/``<kind>``, ``cross``,
+    # ``moe``/``mlp``, ``head``) in the compiled op metadata
+    with jax.named_scope("attn" if kind.startswith("attn") else kind):
+        if kind.startswith("attn"):
+            if decode:
+                kv_keys = [k for k in cache
+                           if not k.startswith("cross")]
+                y, kv = L.attn_decode(p["attn"], cfg, h, positions,
+                                      {k: cache[k] for k in kv_keys},
+                                      kind=kind)
+                new_cache.update(kv)
+            else:
+                y, kv = L.attn_apply(p["attn"], cfg, h, positions, kind=kind,
+                                     make_cache=make_cache)
+                if make_cache:
+                    new_cache = kv
+        elif kind == "rglru":
+            if decode:
+                y, st = L.rglru_decode(p["rglru"], cfg, h,
+                                       {k: cache[k] for k in ("h", "conv")})
+                new_cache.update(st)
+            else:
+                y, st = L.rglru_apply(p["rglru"], cfg, h,
+                                      make_cache=bool(make_cache))
+                if make_cache:
+                    new_cache = st
+        elif kind == "mlstm":
+            if decode:
+                y, st = L.mlstm_decode(p["mlstm"], cfg, h,
+                                       {k: cache[k] for k in ("C", "n", "m")})
+                new_cache.update(st)
+            else:
+                y, st = L.mlstm_apply(p["mlstm"], cfg, h,
+                                      make_cache=bool(make_cache))
+                if make_cache:
+                    new_cache = st
+        elif kind == "slstm":
+            if decode:
+                y, st = L.slstm_decode(p["slstm"], cfg, h,
+                                       {k: cache[k]
+                                        for k in ("c", "n", "h", "m")})
+                new_cache.update(st)
+            else:
+                y, st = L.slstm_apply(p["slstm"], cfg, h,
+                                      make_cache=bool(make_cache))
+                if make_cache:
+                    new_cache = st
     if cfg.sandwich_norm and kind.startswith("attn"):
         y = L.rms_norm(y, p["ln1_post"], cfg.norm_eps)
     x = x + y
 
     if "cross" in p:
         h = L.rms_norm(x, p["ln_cross"], cfg.norm_eps)
-        if decode:
-            y, _ = L.attn_decode(
-                p["cross"], cfg, h, positions, cache, kind="attn_cross",
-                encoder_kv=(cache["cross_k"], cache["cross_v"]))
-        else:
-            ek = L.dot(enc_out, p["cross"]["wk"]).reshape(
-                enc_out.shape[0], enc_out.shape[1], cfg.n_kv_heads,
-                cfg.head_dim)
-            ev = L.dot(enc_out, p["cross"]["wv"]).reshape(ek.shape)
-            if cfg.qkv_bias:
-                ek = ek + p["cross"]["bk"].reshape(ek.shape[-2:])
-                ev = ev + p["cross"]["bv"].reshape(ev.shape[-2:])
-            y, _ = L.attn_apply(p["cross"], cfg, h, positions,
-                                kind="attn_cross", encoder_kv=(ek, ev))
-            if make_cache:
-                new_cache["cross_k"] = ek
-                new_cache["cross_v"] = ev
+        with jax.named_scope("cross"):
+            if decode:
+                y, _ = L.attn_decode(
+                    p["cross"], cfg, h, positions, cache, kind="attn_cross",
+                    encoder_kv=(cache["cross_k"], cache["cross_v"]))
+            else:
+                ek = L.dot(enc_out, p["cross"]["wk"]).reshape(
+                    enc_out.shape[0], enc_out.shape[1], cfg.n_kv_heads,
+                    cfg.head_dim)
+                ev = L.dot(enc_out, p["cross"]["wv"]).reshape(ek.shape)
+                if cfg.qkv_bias:
+                    ek = ek + p["cross"]["bk"].reshape(ek.shape[-2:])
+                    ev = ev + p["cross"]["bv"].reshape(ev.shape[-2:])
+                y, _ = L.attn_apply(p["cross"], cfg, h, positions,
+                                    kind="attn_cross", encoder_kv=(ek, ev))
+                if make_cache:
+                    new_cache["cross_k"] = ek
+                    new_cache["cross_v"] = ev
         x = x + y
 
     if "mlp" in p or "moe" in p:
         h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
         if "moe" in p:
-            y, aux = L.moe_apply(p["moe"], cfg, h)
+            with jax.named_scope("moe"):
+                y, aux = L.moe_apply(p["moe"], cfg, h)
         else:
-            y = L.mlp_apply(p["mlp"], h)
+            with jax.named_scope("mlp"):
+                y = L.mlp_apply(p["mlp"], h)
         if cfg.sandwich_norm:
             y = L.rms_norm(y, p["ln2_post"], cfg.norm_eps)
         x = x + y
@@ -496,9 +502,11 @@ def embed_tokens(cfg, params, tokens):
 
 
 def _head(cfg, params, h):
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("...d,dv->...v", h, w, preferred_element_type=f32)
-    return L.softcap(logits, cfg.final_softcap)
+    with jax.named_scope("head"):
+        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("...d,dv->...v", h, w,
+                            preferred_element_type=f32)
+        return L.softcap(logits, cfg.final_softcap)
 
 
 def forward(cfg: ArchConfig, params, tokens, *, patch_embeds=None,

@@ -43,7 +43,8 @@ UNATTRIBUTED = "(unattributed)"
 _PHASE_ORDER = ["request", "enqueue", "queue_wait", "dispatch",
                 "zygote_boot", "spawn_app", "preload", "fork", "import",
                 "invoke", "cold_start", "engine_cold_start",
-                "engine_serve", UNATTRIBUTED]
+                "engine_serve", "engine_prefill", "engine_route",
+                "engine_decode", "engine_readback", UNATTRIBUTED]
 
 
 def _coerce(spans: Iterable) -> List[Span]:

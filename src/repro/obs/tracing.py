@@ -9,12 +9,24 @@ land on the same clock as the daemon's, so a child's ``fork``/``import``
 spans nest correctly inside the parent's ``dispatch`` span after the
 round-trip over the exec protocol.
 
-The :class:`Tracer` keeps finished spans in a bounded, thread-safe
-ring buffer (oldest spans drop first; ``dropped`` counts them).  It is
-**disabled by default**: ``tracer.span(...)`` returns a shared no-op
-handle without allocating, so instrumentation left in hot paths costs
-one attribute load and one branch (benchmarked in
-``benchmarks/bench_profiler_overhead.py``).
+One :class:`Tracer` feeds two sinks:
+
+* its own bounded, thread-safe ring buffer of finished spans (oldest
+  spans drop first; ``dropped`` counts them), on while ``enabled``;
+* the JAX profiler, while a profiler session records
+  (``jax.profiler.TraceAnnotation.is_enabled()``): each span is also a
+  host event of the same name and attributes, on the clock of the
+  device trace, nested by time on its thread.  The check looks jax up
+  in ``sys.modules`` and never imports it.
+
+Both are off by default: ``tracer.span(...)`` then returns a shared
+no-op handle without allocating, so instrumentation left in hot paths
+costs one profiler check and one branch.  Measured on the host of one
+TPU v5e: a span costs about 0.4 us with both sinks off, 15 us into the
+ring buffer and 2.8 us into a profiler session; serving
+granite-moe-1b-a400m warm (32-token requests, six spans each), the
+median of six rounds read 4.482 ms/token off, 4.484 with the ring
+buffer on and 4.507 with the profiler recording.
 
 Spans serialize to plain dicts (:meth:`Span.to_dict`) so they can ride
 the zygote stdio/socket protocol as a ``spans`` field on exec replies
@@ -24,6 +36,7 @@ and round-trip through the ``trace_events`` artifact.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -105,18 +118,38 @@ def span_dict(name: str, *, trace_id: str, parent_id: Optional[str],
                 attrs=dict(attrs)).to_dict()
 
 
+_ANNOTATION = None
+
+
+def _profiler_annotation():
+    """JAX's ``TraceAnnotation`` while a profiler session records, else
+    None.  Found in ``sys.modules``, so a process that has not imported
+    jax (a Level-A zygote) never imports it here."""
+    global _ANNOTATION
+    ann = _ANNOTATION
+    if ann is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is None:
+            return None
+        ann = _ANNOTATION = prof.TraceAnnotation
+    return ann if ann.is_enabled() else None
+
+
 class _SpanHandle:
     """Context manager that records a span on exit.
 
     ``handle.ctx()`` gives the ``{"trace_id", "parent_id"}`` dict to
-    hand to children (including across the zygote protocol).
+    hand to children (including across the zygote protocol).  ``host``
+    is the span's open profiler event, if a profiler session records;
+    ending the handle closes it.
     """
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "span", "_host")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, host=None):
         self._tracer = tracer
         self.span = span
+        self._host = host
 
     @property
     def span_id(self) -> str:
@@ -132,6 +165,8 @@ class _SpanHandle:
 
     def set(self, key: str, value: object) -> "_SpanHandle":
         self.span.attrs[key] = value
+        if self._host is not None:
+            self._host.set_metadata(**{key: value})
         return self
 
     def __enter__(self) -> "_SpanHandle":
@@ -141,6 +176,9 @@ class _SpanHandle:
         self.end()
 
     def end(self) -> None:
+        if self._host is not None:
+            self._host.__exit__(None, None, None)
+            self._host = None
         if self.span.duration_ms == 0.0:
             self.span.duration_ms = now_ms() - self.span.t_start_ms
         self._tracer.record(self.span)
@@ -175,6 +213,29 @@ class _NoopHandle:
 _NOOP = _NoopHandle()
 
 
+class _HostEventHandle(_NoopHandle):
+    """A span with the ring buffer off and a profiler session on: the
+    profiler's host event alone (no ids, so no ``ctx``)."""
+
+    __slots__ = ("_host",)
+
+    def __init__(self, host):
+        self._host = host
+
+    def set(self, key, value):
+        if self._host is not None:
+            self._host.set_metadata(**{key: value})
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.end()
+
+    def end(self):
+        if self._host is not None:
+            self._host.__exit__(None, None, None)
+            self._host = None
+
+
 class Tracer:
     """Thread-safe bounded collector of finished spans."""
 
@@ -200,28 +261,34 @@ class Tracer:
     # -- producing spans -------------------------------------------------
     def span(self, name: str, *, ctx: Optional[dict] = None,
              **attrs: object):
-        """Open a span; returns a no-op handle when disabled.
+        """Open a span in each sink that is on; returns the shared no-op
+        handle when both are off.
 
         ``ctx`` is a ``{"trace_id", "parent_id"}`` dict from a parent
         handle's :meth:`_SpanHandle.ctx` (or off the wire).  Without
-        one, the span starts a fresh trace as its root.
+        one, the span starts a fresh trace as its root.  The profiler's
+        host event nests by time on its thread and ignores ``ctx``.
         """
+        ann = _profiler_annotation()
+        host = None if ann is None else ann(name, **attrs)
         if not self.enabled:
-            return _NOOP
+            return _NOOP if host is None else _HostEventHandle(host)
         trace_id = parent_id = None
         if ctx:
             trace_id = ctx.get("trace_id")
             parent_id = ctx.get("parent_id")
         return _SpanHandle(self, Span(
             name=name, trace_id=trace_id or new_id(), span_id=new_id(),
-            parent_id=parent_id, t_start_ms=now_ms(), attrs=dict(attrs)))
+            parent_id=parent_id, t_start_ms=now_ms(), attrs=dict(attrs)),
+            host)
 
     def add(self, name: str, *, trace_id: str,
             parent_id: Optional[str] = None,
             span_id: Optional[str] = None, t_start_ms: float,
             duration_ms: float, attrs: Optional[dict] = None) -> str:
         """Record a span whose start/duration were measured elsewhere
-        (e.g. queue wait derived from the enqueue timestamp)."""
+        (e.g. queue wait derived from the enqueue timestamp); the ring
+        buffer only, since the profiler records events as they happen."""
         sid = span_id or new_id()
         if self.enabled:
             self.record(Span(name=name, trace_id=trace_id, span_id=sid,
@@ -250,12 +317,6 @@ class Tracer:
     def snapshot(self) -> List[Span]:
         with self._lock:
             return list(self._buf)
-
-    def drain(self) -> List[Span]:
-        with self._lock:
-            out = list(self._buf)
-            self._buf.clear()
-            return out
 
     def clear(self) -> None:
         with self._lock:

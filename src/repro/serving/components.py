@@ -18,6 +18,8 @@ from typing import Any, Callable, Optional
 
 import jax
 
+from repro.obs.tracing import get_tracer
+
 
 @dataclasses.dataclass
 class Component:
@@ -31,13 +33,17 @@ class Component:
     init_time: float = 0.0
     uses: int = 0
 
-    def get(self):
+    def get(self, ctx: Optional[dict] = None):
+        """The component's value, built on first use under a span
+        ``component:<name>`` (``ctx`` parents it)."""
         if not self.ready:
-            t0 = time.perf_counter()
-            self.value = self.build()
-            jax.block_until_ready(jax.tree.leaves(self.value)) \
-                if jax.tree.leaves(self.value) else None
-            self.init_time += time.perf_counter() - t0
+            with get_tracer().span(f"component:{self.name}", ctx=ctx,
+                                   group=self.group):
+                t0 = time.perf_counter()
+                self.value = self.build()
+                jax.block_until_ready(jax.tree.leaves(self.value)) \
+                    if jax.tree.leaves(self.value) else None
+                self.init_time += time.perf_counter() - t0
             self.ready = True
         self.uses += 1
         return self.value
@@ -102,10 +108,11 @@ class ComponentRegistry:
     def values(self):
         return self._comps.values()
 
-    def materialize_eager(self, policy: LoadPolicy):
+    def materialize_eager(self, policy: LoadPolicy,
+                          ctx: Optional[dict] = None):
         for comp in self._comps.values():
             if not policy.is_lazy(comp):
-                comp.get()
+                comp.get(ctx)
                 comp.uses -= 1  # startup materialization isn't a use
 
     # ---------------------------------------------------- init hierarchy

@@ -38,6 +38,14 @@ from repro.serving.components import Component, ComponentRegistry, LoadPolicy
 
 # step programs behind the entry points (jitted per engine with the
 # leading arguments bound, see ServingEngine.entry_programs)
+def _jit_named(fn: Callable, *bound) -> Callable:
+    """``jax.jit(partial(fn, *bound))`` whose XLA module is named
+    ``jit_<fn.__name__>`` (a bare partial compiles as ``jit__unknown``)."""
+    step = partial(fn, *bound)
+    step.__name__ = fn.__name__
+    return jax.jit(step)
+
+
 def score_step(cfg: ArchConfig, params, tokens):
     """Teacher-forced logits (B, S, V) for every position."""
     h, _, _ = forward(cfg, params, tokens)
@@ -187,14 +195,13 @@ class ServingEngine:
         toks, extras = self._entry_shapes(entry)
         params = self._param_shapes()
         if entry == "score":
-            return {"score": (jax.jit(partial(score_step, cfg)),
-                              (params, toks))}
+            return {"score": (_jit_named(score_step, cfg), (params, toks))}
         cache_len = self.max_len + (cfg.vision_tokens or 0)
         caches = jax.eval_shape(lambda: init_cache(cfg, self.B, cache_len))
         return {
-            "prefill": (jax.jit(partial(prefill_step, cfg, cache_len)),
+            "prefill": (_jit_named(prefill_step, cfg, cache_len),
                         (params, toks, extras)),
-            "decode": (jax.jit(partial(decode_next, cfg)),
+            "decode": (_jit_named(decode_next, cfg),
                        (params,
                         jax.ShapeDtypeStruct((self.B, 1), jnp.int32),
                         jax.ShapeDtypeStruct((self.B,), jnp.int32),
@@ -210,63 +217,85 @@ class ServingEngine:
             lambda: init_params(self.cfg, jax.random.PRNGKey(0)))
 
     # ---------------------------------------------------------- serving
-    def cold_start(self):
-        """Materialize the eager set; returns wall seconds."""
-        t0 = time.perf_counter()
-        self._params = self.registry["weights.core"].get()
-        self.registry["weights.core"].uses -= 1
-        self.registry.materialize_eager(self.policy)
-        self.cold_start_s = time.perf_counter() - t0
+    def cold_start(self, ctx: Optional[dict] = None):
+        """Materialize the eager set; returns wall seconds.  ``ctx``
+        parents the ``engine_cold_start`` span (the pool's
+        ``cold_start``)."""
+        with get_tracer().span("engine_cold_start", ctx=ctx) as sp:
+            t0 = time.perf_counter()
+            weights = self.registry["weights.core"]
+            self._params = weights.get(sp.ctx())
+            weights.uses -= 1
+            self.registry.materialize_eager(self.policy, sp.ctx())
+            self.cold_start_s = time.perf_counter() - t0
         return self.cold_start_s
 
-    def _ensure(self, name: str):
-        comp = self.registry[name]
-        return comp.get()
-
     def serve(self, entry: str, tokens: np.ndarray, *,
-              max_new_tokens: int = 8, extras: Optional[dict] = None):
-        """Serve one batched request; returns (tokens_out, latency_s)."""
+              max_new_tokens: int = 8, extras: Optional[dict] = None,
+              ctx: Optional[dict] = None):
+        """Serve one batched request; returns (tokens_out, latency_s).
+
+        Spans: ``engine_serve`` (``ctx`` parents it) and, for a
+        generating entry, its children ``engine_prefill`` (input
+        transfer and the prefill program), ``engine_route`` (the
+        router-load read-back, MoE only), ``engine_decode`` (the host
+        token loop) and ``engine_readback`` (the tokens to the host)."""
         t0 = time.perf_counter()
+        new_tokens = 0 if entry == "score" else max_new_tokens
+        with get_tracer().span("engine_serve", ctx=ctx, entry=entry,
+                               new_tokens=new_tokens) as sp:
+            out = self._serve(entry, tokens, max_new_tokens, extras,
+                              sp.ctx())
+        return out, time.perf_counter() - t0
+
+    def _serve(self, entry: str, tokens: np.ndarray, max_new_tokens: int,
+               extras: Optional[dict], ctx: Optional[dict]):
+        tracer = get_tracer()
         cfg = self.cfg
         self.entry_counts[entry] = self.entry_counts.get(entry, 0) + 1
+        weights = self.registry["weights.core"]
         if self._params is None:
-            self._params = self.registry["weights.core"].get()
-            self.registry["weights.core"].uses -= 1  # counted below
-        exes = self._ensure(f"compile.{entry}")
+            self._params = weights.get(ctx)
+            weights.uses -= 1  # counted below
+        exes = self.registry[f"compile.{entry}"].get(ctx)
         if entry == "vision_generate":
-            self._ensure("frontend.vision")
+            self.registry["frontend.vision"].get(ctx)
         if entry == "transcribe":
-            self._ensure("frontend.audio_encoder")
+            self.registry["frontend.audio_encoder"].get(ctx)
 
-        self.registry["weights.core"].uses += 1  # every request hits them
-        toks = jnp.asarray(tokens, jnp.int32)
+        weights.uses += 1  # every request hits them
         if entry == "score":
-            out = exes["score"](self._params, toks)
+            out = exes["score"](self._params, jnp.asarray(tokens, jnp.int32))
             jax.block_until_ready(out)
-            return np.asarray(out), time.perf_counter() - t0
+            return np.asarray(out)
 
-        extra = dict(extras or {})
-        _, extra_shapes = self._entry_shapes(entry)
-        for k, sds in extra_shapes.items():
-            if k not in extra:
-                extra[k] = jnp.zeros(sds.shape, sds.dtype)
-
-        nxt, caches, load = exes["prefill"](self._params, toks, extra)
+        with tracer.span("engine_prefill", ctx=ctx):
+            toks = jnp.asarray(tokens, jnp.int32)
+            extra = dict(extras or {})
+            _, extra_shapes = self._entry_shapes(entry)
+            for k, sds in extra_shapes.items():
+                if k not in extra:
+                    extra[k] = jnp.zeros(sds.shape, sds.dtype)
+            nxt, caches, load = exes["prefill"](self._params, toks, extra)
         if load is not None:
-            self._account_experts(np.asarray(load))
+            with tracer.span("engine_route", ctx=ctx) as sp:
+                self._account_experts(np.asarray(load), sp.ctx())
         vt = cfg.vision_tokens if entry == "vision_generate" else 0
         pos0 = toks.shape[1] + (vt or 0)
         out = [nxt]
         tok = nxt[:, None]
-        for i in range(max_new_tokens - 1):
-            pos = jnp.full((self.B,), pos0 + i, jnp.int32)
-            tok, caches = exes["decode"](self._params, tok, pos, caches)
-            out.append(tok[:, 0])
-        result = np.stack([np.asarray(o) for o in out], axis=1)
-        return result, time.perf_counter() - t0
+        with tracer.span("engine_decode", ctx=ctx,
+                         steps=max_new_tokens - 1):
+            for i in range(max_new_tokens - 1):
+                pos = jnp.full((self.B,), pos0 + i, jnp.int32)
+                tok, caches = exes["decode"](self._params, tok, pos, caches)
+                out.append(tok[:, 0])
+        with tracer.span("engine_readback", ctx=ctx):
+            return np.stack([np.asarray(o) for o in out], axis=1)
 
     # ----------------------------------------- utilization / SLIMSTART
-    def _account_experts(self, load: np.ndarray):
+    def _account_experts(self, load: np.ndarray,
+                         ctx: Optional[dict] = None):
         """Routing mass -> expert Component.uses; materialize experts
         that received traffic but are still cold (lazy loading)."""
         if self.expert_mass is None:
@@ -277,7 +306,7 @@ class ServingEngine:
             if name in self.registry and mass > 0:
                 comp = self.registry[name]
                 if not comp.ready:
-                    comp.get()  # deferred materialization on first route
+                    comp.get(ctx)  # deferred materialization on first route
                 else:
                     comp.uses += 1
 
@@ -388,17 +417,17 @@ class EnginePool:
         if eng is not None:
             self.hits += 1
             self._dispatches[model] = self._dispatches.get(model, 0) + 1
-            out, lat = eng.serve(entry, tokens, **kw)
+            out, lat = eng.serve(entry, tokens, ctx=_ctx, **kw)
             return out, lat, "warm"
         self.misses += 1
-        with get_tracer().span("cold_start", ctx=_ctx, model=model):
+        with get_tracer().span("cold_start", ctx=_ctx, model=model) as cs:
             if self.fault_hook is not None:
                 self.fault_hook("cold_start", app=model)
             eng = self.builders[model]()
-            cold_s = eng.cold_start()
+            cold_s = eng.cold_start(ctx=cs.ctx())
         self._admit(model, eng)
         self._dispatches[model] = self._dispatches.get(model, 0) + 1
-        out, lat = eng.serve(entry, tokens, **kw)
+        out, lat = eng.serve(entry, tokens, ctx=_ctx, **kw)
         return out, lat + cold_s, "cold"
 
     def _dispatch_queued(self, model: str, entry: str, tokens,
@@ -434,16 +463,17 @@ class EnginePool:
                     evt = self._cold_events[model]
                     path = "wait"
             if path in ("warm", "queued"):
-                out, lat = self._serve_counted(eng, entry, tokens, **kw)
+                out, lat = self._serve_counted(eng, entry, tokens,
+                                               ctx=_ctx, **kw)
                 return out, lat + wait_s, path
             if path == "build":
                 try:
                     with get_tracer().span("cold_start", ctx=_ctx,
-                                           model=model):
+                                           model=model) as cs:
                         if self.fault_hook is not None:
                             self.fault_hook("cold_start", app=model)
                         eng = self.builders[model]()
-                        cold_s = eng.cold_start()
+                        cold_s = eng.cold_start(ctx=cs.ctx())
                     with self._lock:
                         self.misses += 1
                         self._admit(model, eng)
@@ -454,7 +484,8 @@ class EnginePool:
                     # retries as the next builder
                     with self._lock:
                         self._cold_events.pop(model).set()
-                out, lat = self._serve_counted(eng, entry, tokens, **kw)
+                out, lat = self._serve_counted(eng, entry, tokens,
+                                               ctx=_ctx, **kw)
                 return out, lat + cold_s, "cold"
             # path == "wait": block until the in-flight build finishes
             evt.wait()

@@ -307,7 +307,7 @@ class _StubEngine:
         self.cold_start_s = None
         self.registry = {}
 
-    def cold_start(self):
+    def cold_start(self, ctx=None):
         time.sleep(self._cold_s)
         self.cold_start_s = self._cold_s
         return self._cold_s

@@ -466,7 +466,7 @@ class _InstantEngine:
         self.cold_start_s = None
         self.registry = {}
 
-    def cold_start(self):
+    def cold_start(self, ctx=None):
         self.cold_start_s = 0.001
         return self.cold_start_s
 
@@ -499,7 +499,7 @@ def test_engine_pool_stats_breaks_out_pool_saturated_sheds():
     from repro.serving.engine import EnginePool, PoolSaturated
 
     class _SlowColdEngine(_InstantEngine):
-        def cold_start(self):
+        def cold_start(self, ctx=None):
             time.sleep(0.2)
             self.cold_start_s = 0.2
             return self.cold_start_s

@@ -182,7 +182,7 @@ def _fault_hook_overhead(n: int = 4000, runs: int = 3):
         cold_start_s = 0.0   # read by the eviction amortizer
         registry = {}        # no components to drop on eviction
 
-        def cold_start(self):
+        def cold_start(self, ctx=None):
             return 0.0
 
         def serve(self, entry, tokens, **kw):
