@@ -3,8 +3,10 @@
 The model keeps GQA activations as (B, S, K, G, hd); these wrappers
 transpose into kernel layout, invoke the kernel (interpret=True on CPU
 so the kernel body is executed for validation; compiled on real TPU),
-and transpose back.  They are drop-in replacements for the XLA-path
-attention in ``repro.models.layers`` when ``cfg.attn_impl == "pallas"``.
+and transpose back.  The attention ops are drop-in replacements for the
+XLA-path attention in ``repro.models.layers`` when
+``cfg.attn_impl == "pallas"``; ``moe_routed_op`` is what
+``layers.moe_apply`` calls where it takes the routed path.
 """
 
 from __future__ import annotations
@@ -16,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.moe_decode import moe_routed_decode
 from repro.kernels.rglru_scan import rglru_scan
 
 
@@ -51,3 +54,12 @@ def decode_attention_op(q, k, v, q_pos, kv_pos, *, window=None,
 def rglru_op(a, gated, h0=None):
     """Diagonal linear recurrence in model layout (B, S, R)."""
     return rglru_scan(a, gated, h0, interpret=_on_cpu())
+
+
+@jax.jit
+def moe_routed_op(x, wi, wo, layer, ids, weights):
+    """x: (N, D) tokens; wi: (n_stack, E, D, 2F), wo: (n_stack, E, F, D)
+    expert stacks, of which ``layer`` is used; ids, weights: (N, k)
+    routed experts and their combine weights -> (N, D)."""
+    return moe_routed_decode(x, wi, wo, layer, ids, weights,
+                             interpret=_on_cpu())

@@ -70,3 +70,19 @@ def ref_rglru_scan(a, b, h0=None):
 
     _, h = jax.lax.associative_scan(combine, (a32, b32), axis=1)
     return h.astype(a.dtype)
+
+
+def ref_moe_routed(x, wi, wo, layer, ids, weights):
+    """x: (N, D); wi: (n_stack, E, D, 2F); wo: (n_stack, E, F, D);
+    ids, weights: (N, k).  Each token's routed experts of layer
+    ``layer``, combined by ``weights`` (casts as the capacity MoE)."""
+    f32 = jnp.float32
+    F = wo.shape[2]
+    wi_k, wo_k = wi[layer][ids], wo[layer][ids]  # (N, k, D, 2F), (N, k, F, D)
+    gu = jnp.einsum("nd,nkdf->nkf", x, wi_k,
+                    preferred_element_type=f32).astype(x.dtype)
+    g, u = gu[..., :F], gu[..., F:]
+    h = jax.nn.gelu(g.astype(f32)).astype(x.dtype) * u
+    hout = jnp.einsum("nkf,nkfd->nkd", h, wo_k, preferred_element_type=f32)
+    y = jnp.einsum("nk,nkd->nd", weights.astype(f32), hout)
+    return y.astype(x.dtype)

@@ -17,8 +17,10 @@ norms / softmax / recurrences run in fp32 and cast back.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
+import threading
 from functools import partial
 from typing import Any, Optional
 
@@ -27,7 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.models.config import ArchConfig
-from repro.models.partition import constrain
+from repro.models.partition import _current_mesh, constrain
 
 f32 = jnp.float32
 
@@ -473,12 +475,45 @@ def moe_template(cfg: ArchConfig):
     }
 
 
-def moe_apply(p, cfg, x, group_size=None):
-    """Switch-style capacity-based MoE with grouped one-hot dispatch.
+def moe_takes_routed_path(n_tokens: int, group: int, cap: int,
+                          n_experts: int, top_k: int) -> bool:
+    """Whether ``moe_apply`` reads only the routed experts' weights.
 
-    x: (B, S, D).  Returns (y, aux) where aux carries the router load
-    (per-expert probability mass — the Level-B utilization signal) and
-    the load-balancing loss term.
+    Taken when it reads fewer expert blocks than the capacity dispatch
+    (``n_tokens * top_k < n_experts``), when no (token, slot) pair can
+    be dropped (``cap >= group``), so the result is the capacity
+    path's, and when no mesh is active: the kernel reads whole expert
+    blocks of an unsharded stack.
+    """
+    return (n_tokens * top_k < n_experts and cap >= group
+            and _current_mesh() is None)
+
+
+_paths = threading.local()
+
+
+@contextlib.contextmanager
+def moe_paths():
+    """Collect the path ("routed" / "capacity") each ``moe_apply``
+    traced inside the block takes, into the set it yields."""
+    prev = getattr(_paths, "seen", None)
+    _paths.seen = seen = set()
+    try:
+        yield seen
+    finally:
+        _paths.seen = prev
+
+
+def moe_apply(p, cfg, x, group_size=None, layer=None):
+    """Switch-style capacity-based MoE with grouped one-hot dispatch, or
+    (where ``moe_takes_routed_path``) the routed-expert kernel.
+
+    x: (B, S, D).  ``layer``: when given, ``p["wi"]``/``p["wo"]`` are the
+    whole (n_stack, E, ...) expert stacks and ``layer`` indexes them (the
+    decode loop passes them so: a slice cannot fuse into the kernel).
+    Returns (y, aux) where aux carries the router load (per-expert
+    probability mass — the Level-B utilization signal) and the
+    load-balancing loss term.
     """
     e = cfg.moe
     B, S, D = x.shape
@@ -495,29 +530,23 @@ def moe_apply(p, cfg, x, group_size=None):
 
     cap = max(int(e.capacity_factor * gs * e.top_k / e.n_experts), 1)
     onehot = jax.nn.one_hot(top_e, e.n_experts, dtype=f32)  # (G,S,k,E)
-    # position of each (token, slot) within its expert queue
-    flat = onehot.reshape(G, gs * e.top_k, e.n_experts)
-    pos = (jnp.cumsum(flat, axis=1) - flat).reshape(G, gs, e.top_k,
-                                                    e.n_experts)
-    keep = (pos < cap) * onehot
-    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=f32)
-    disp = jnp.einsum("gske,gskec->gsec", keep, pos_oh)  # (G,S,E,C)
-    comb = jnp.einsum("gsk,gske,gskec->gsec", top_p, keep, pos_oh)
-    # dispatch tensors: token groups over DP, experts over the EP axis;
-    # bf16 is plenty for one-hot routing masks and halves their footprint
-    disp = constrain(disp.astype(x.dtype), "batch", None, "experts", None)
-    comb = constrain(comb.astype(f32), "batch", None, "experts", None)
-
-    xin = jnp.einsum("gsec,gsd->egcd", disp.astype(f32), xg.astype(f32),
-                     preferred_element_type=f32).astype(x.dtype)
-    xin = constrain(xin, "experts", "batch", None, "embed")
-    gu = jnp.einsum("egcd,edf->egcf", xin, p["wi"],
-                    preferred_element_type=f32).astype(x.dtype)
-    g, u = jnp.split(gu, 2, axis=-1)
-    h = jax.nn.gelu(g.astype(f32)).astype(x.dtype) * u
-    hout = jnp.einsum("egcf,efd->egcd", h, p["wo"],
-                      preferred_element_type=f32)
-    y = jnp.einsum("gsec,egcd->gsd", comb, hout).astype(x.dtype)
+    routed = moe_takes_routed_path(N, gs, cap, e.n_experts, e.top_k)
+    if getattr(_paths, "seen", None) is not None:
+        _paths.seen.add("routed" if routed else "capacity")
+    wi, wo = p["wi"], p["wo"]
+    if routed:
+        # imported on use: Pallas adds seconds to a process's import time
+        from repro.kernels.ops import moe_routed_op
+        if layer is None:
+            wi, wo, layer = wi[None], wo[None], 0
+        y = moe_routed_op(x.reshape(N, D), wi, wo, layer,
+                          top_e.reshape(N, e.top_k),
+                          top_p.reshape(N, e.top_k))
+    else:
+        if layer is not None:
+            wi = lax.dynamic_index_in_dim(wi, layer, 0, keepdims=False)
+            wo = lax.dynamic_index_in_dim(wo, layer, 0, keepdims=False)
+        y = _moe_capacity(xg, wi, wo, top_p, onehot, cap)
 
     # aux: per-expert routed mass and Switch load-balancing loss
     load = onehot.sum((0, 1, 2)) / (N * e.top_k)  # fraction dispatched
@@ -525,6 +554,33 @@ def moe_apply(p, cfg, x, group_size=None):
     aux_loss = e.n_experts * jnp.sum(load * importance)
     aux = {"expert_load": load, "moe_aux_loss": aux_loss}
     return y.reshape(B, S, D), aux
+
+
+def _moe_capacity(xg, wi, wo, top_p, onehot, cap):
+    """One-hot capacity dispatch over every expert.  xg: (G, gs, D)."""
+    G, gs, k, E = onehot.shape
+    # position of each (token, slot) within its expert queue
+    flat = onehot.reshape(G, gs * k, E)
+    pos = (jnp.cumsum(flat, axis=1) - flat).reshape(G, gs, k, E)
+    keep = (pos < cap) * onehot
+    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), cap, dtype=f32)
+    disp = jnp.einsum("gske,gskec->gsec", keep, pos_oh)  # (G,S,E,C)
+    comb = jnp.einsum("gsk,gske,gskec->gsec", top_p, keep, pos_oh)
+    # dispatch tensors: token groups over DP, experts over the EP axis;
+    # bf16 is plenty for one-hot routing masks and halves their footprint
+    disp = constrain(disp.astype(xg.dtype), "batch", None, "experts", None)
+    comb = constrain(comb.astype(f32), "batch", None, "experts", None)
+
+    xin = jnp.einsum("gsec,gsd->egcd", disp.astype(f32), xg.astype(f32),
+                     preferred_element_type=f32).astype(xg.dtype)
+    xin = constrain(xin, "experts", "batch", None, "embed")
+    gu = jnp.einsum("egcd,edf->egcf", xin, wi,
+                    preferred_element_type=f32).astype(xg.dtype)
+    g, u = jnp.split(gu, 2, axis=-1)
+    h = jax.nn.gelu(g.astype(f32)).astype(xg.dtype) * u
+    hout = jnp.einsum("egcf,efd->egcd", h, wo,
+                      preferred_element_type=f32)
+    return jnp.einsum("gsec,egcd->gsd", comb, hout).astype(xg.dtype)
 
 
 # --------------------------------------------------------------------------

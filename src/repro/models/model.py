@@ -239,8 +239,10 @@ def init_cache(cfg: ArchConfig, batch: int, cache_len: int):
 # --------------------------------------------------------------------------
 
 def _apply_block(p, cfg, kind, x, positions, *, cache=None, decode=False,
-                 make_cache=0, enc_out=None):
-    """One residual block.  Returns (x, new_cache, aux)."""
+                 make_cache=0, enc_out=None, layer=None):
+    """One residual block.  Returns (x, new_cache, aux).  ``layer``: the
+    index into the whole expert stacks that ``p["moe"]`` then holds
+    (see ``_decode_layers_inplace``)."""
     aux = {}
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     new_cache = dict(cache) if cache is not None else None
@@ -321,7 +323,7 @@ def _apply_block(p, cfg, kind, x, positions, *, cache=None, decode=False,
         h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
         if "moe" in p:
             with jax.named_scope("moe"):
-                y, aux = L.moe_apply(p["moe"], cfg, h)
+                y, aux = L.moe_apply(p["moe"], cfg, h, layer=layer)
         else:
             with jax.named_scope("mlp"):
                 y = L.mlp_apply(p["mlp"], h)
@@ -362,20 +364,30 @@ def _decode_layers_inplace(cfg, params_scan, x, positions, caches_scan,
 
     Caches are updated with dynamic_update_index_in_dim so XLA keeps the
     multi-GB KV buffers in place through the while loop (a scan emitting
-    new caches as ys would double-buffer them).
+    new caches as ys would double-buffer them).  The expert weights stay
+    whole stacks indexed by the layer inside ``moe_apply``: XLA fuses a
+    slice of them into the capacity einsums, but not into the routed
+    kernel, which would then copy one layer's experts per step.
     """
     def at(tree, t):
         return jax.tree.map(
             lambda a: lax.dynamic_index_in_dim(a, t, 0, keepdims=False),
             tree)
 
+    def block_at(p, t):
+        out = at({k: v for k, v in p.items() if k != "moe"}, t)
+        if "moe" in p:
+            out["moe"] = {k: v if k in ("wi", "wo") else at(v, t)
+                          for k, v in p["moe"].items()}
+        return out
+
     def body(t, carry):
         x, caches = carry
-        p_t = at(params_scan, t)
         for i, kind in enumerate(pattern):
             c_t = at(caches[f"pos{i}"], t)
-            x, nc, _ = _apply_block(p_t[f"pos{i}"], cfg, kind, x,
-                                    positions, cache=c_t, decode=True)
+            x, nc, _ = _apply_block(block_at(params_scan[f"pos{i}"], t),
+                                    cfg, kind, x, positions, cache=c_t,
+                                    decode=True, layer=t)
             # write back only entries the block actually changed —
             # re-writing static slices (whisper's cross K/V: ~2 GB per
             # layer) would force XLA to copy them every loop iteration

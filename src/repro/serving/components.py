@@ -32,18 +32,22 @@ class Component:
     ready: bool = False
     init_time: float = 0.0
     uses: int = 0
+    # attributes of the build, read after it, for its span
+    span_attrs: Optional[Callable[[], dict]] = None
 
     def get(self, ctx: Optional[dict] = None):
         """The component's value, built on first use under a span
         ``component:<name>`` (``ctx`` parents it)."""
         if not self.ready:
             with get_tracer().span(f"component:{self.name}", ctx=ctx,
-                                   group=self.group):
+                                   group=self.group) as sp:
                 t0 = time.perf_counter()
                 self.value = self.build()
                 jax.block_until_ready(jax.tree.leaves(self.value)) \
                     if jax.tree.leaves(self.value) else None
                 self.init_time += time.perf_counter() - t0
+                for key, val in (self.span_attrs or dict)().items():
+                    sp.set(key, val)
             self.ready = True
         self.uses += 1
         return self.value

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.config import ArchConfig
+from repro.models.layers import moe_paths
 from repro.models.model import (
     _head, decode_step, forward, init_cache, init_params, prefill,
 )
@@ -103,6 +104,8 @@ class ServingEngine:
         self.expert_mass: Optional[np.ndarray] = None
         self._params = None
         self.cold_start_s: Optional[float] = None
+        # entry -> the MoE paths its compiled programs took
+        self.moe_paths: dict[str, set] = {}
         self._build_components()
 
     # ------------------------------------------------------------ build
@@ -137,7 +140,9 @@ class ServingEngine:
         # module that serves this handler)
         for entry in self.entries():
             reg.add(Component(f"compile.{entry}", "compile",
-                              partial(self._compile_entry, entry)))
+                              partial(self._compile_entry, entry),
+                              span_attrs=partial(self._compile_attrs,
+                                                 entry)))
 
     def entries(self) -> list[str]:
         cfg = self.cfg
@@ -209,8 +214,21 @@ class ServingEngine:
         }
 
     def _compile_entry(self, entry: str):
-        return {name: fn.lower(*args).compile()
-                for name, (fn, args) in self.entry_programs(entry).items()}
+        with moe_paths() as paths:
+            exes = {name: fn.lower(*args).compile()
+                    for name, (fn, args) in self.entry_programs(entry).items()}
+        self.moe_paths[entry] = paths
+        return exes
+
+    def _compile_attrs(self, entry: str) -> dict:
+        """``moe_path`` of an entry's compile: ``routed`` where one of its
+        programs reads only the routed experts (decode at batch 1),
+        ``capacity`` where all its MoE layers dispatch by capacity;
+        absent without MoE."""
+        paths = self.moe_paths.get(entry)
+        if not paths:
+            return {}
+        return {"moe_path": "routed" if "routed" in paths else "capacity"}
 
     def _param_shapes(self):
         return jax.eval_shape(

@@ -197,3 +197,150 @@ def test_attention_op_matches_model_layer():
                                   softcap=cap)
         np.testing.assert_allclose(np.asarray(pallas), np.asarray(xla),
                                    rtol=2e-3, atol=2e-3)
+
+
+# ------------------------------------------------------- routed-expert MoE
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("E", [8, 32])
+@pytest.mark.parametrize("k", [1, 2, 8])
+@pytest.mark.parametrize("n_stack,layer", [(1, 0), (3, 0), (3, 2)])
+def test_moe_routed_decode_vs_ref(n_stack, layer, k, E, dtype):
+    from repro.kernels.moe_decode import moe_routed_decode
+    key = jax.random.PRNGKey(4)
+    N, D, F = 2, 64, 32
+    x = _rand(key, (N, D), dtype)
+    wi = (_rand(jax.random.fold_in(key, 1), (n_stack, E, D, 2 * F),
+                jnp.float32) * D ** -0.5).astype(dtype)
+    wo = (_rand(jax.random.fold_in(key, 2), (n_stack, E, F, D),
+                jnp.float32) * F ** -0.5).astype(dtype)
+    ids = jnp.stack([jax.random.permutation(jax.random.fold_in(key, 3 + n),
+                                            E)[:k] for n in range(N)])
+    w = jax.nn.softmax(_rand(jax.random.fold_in(key, 9), (N, k),
+                             jnp.float32))
+    out = moe_routed_decode(x, wi, wo, jnp.int32(layer), ids, w,
+                            interpret=True)
+    want = ref.ref_moe_routed(x, wi, wo, layer, ids, w)
+    assert out.shape == (N, D) and out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32), **TOL[dtype])
+
+
+def _force_capacity(monkeypatch):
+    from repro.models import layers as L
+    monkeypatch.setattr(L, "moe_takes_routed_path", lambda *a: False)
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+@pytest.mark.parametrize("B", [1, 2])
+@pytest.mark.parametrize("arch", ["granite-moe-1b-a400m", "olmoe-1b-7b"])
+def test_moe_routed_path_matches_capacity_path(monkeypatch, arch, B,
+                                               stacked):
+    """At decode shapes the reduced configs (top-2 of 8, capacity 8.0)
+    take the routed path; it gives the capacity path's result, from a
+    per-layer weight or from the whole stack indexed by the layer."""
+    from repro.configs import get_reduced
+    from repro.models import layers as L
+    from repro.models.model import _stack_specs
+    cfg = get_reduced(arch)
+    key = jax.random.PRNGKey(5)
+    n = 3 if stacked else 1
+    specs = _stack_specs(L.moe_template(cfg), n)
+    p = {name: s.std() * _rand(jax.random.fold_in(key, i), s.shape,
+                               jnp.float32)
+         for i, (name, s) in enumerate(sorted(specs.items()))}
+    x = _rand(jax.random.fold_in(key, 7), (B, 1, cfg.d_model), jnp.float32)
+    layer = 2 if stacked else None
+    flat = {k: v[n - 1] for k, v in p.items()}
+    blk = {**flat, "wi": p["wi"], "wo": p["wo"]} if stacked else flat
+
+    with L.moe_paths() as seen:
+        y, aux = L.moe_apply(blk, cfg, x, layer=layer)
+    assert seen == {"routed"}
+    _force_capacity(monkeypatch)
+    with L.moe_paths() as seen:
+        y_cap, aux_cap = L.moe_apply(flat, cfg, x)
+    assert seen == {"capacity"}
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_cap),
+                               **TOL[jnp.float32])
+    for name in aux:
+        np.testing.assert_array_equal(np.asarray(aux[name]),
+                                      np.asarray(aux_cap[name]))
+
+
+@pytest.fixture(scope="module")
+def moe_engine_pair():
+    """Two engines of the reduced granite-moe on the same weights, one
+    compiled with the routed decode path (key True) and one with it
+    forced off (key False)."""
+    from repro.configs import get_reduced
+    from repro.serving import ServingEngine
+    pair = {}
+    for routed in (True, False):
+        with pytest.MonkeyPatch.context() as mp:
+            if not routed:
+                _force_capacity(mp)
+            eng = ServingEngine(get_reduced("granite-moe-1b-a400m"),
+                                seed=11, prefill_len=16, max_len=40)
+            eng.cold_start()
+        pair[routed] = eng
+    return pair
+
+
+@pytest.mark.parametrize("prompt", [0, 1, 2])
+def test_engine_greedy_tokens_do_not_depend_on_the_moe_path(
+        monkeypatch, moe_engine_pair, prompt):
+    """The same greedy tokens with the routed decode path on and forced
+    off, and the decode step's logits equal to f32 rounding."""
+    from repro.models.model import decode_step, prefill
+    on, off = moe_engine_pair[True], moe_engine_pair[False]
+    assert on.moe_paths["generate"] == {"routed", "capacity"}
+    assert off.moe_paths["generate"] == {"capacity"}
+    cfg, params = on.cfg, on._params
+    toks = np.random.default_rng(prompt).integers(0, cfg.vocab, (1, 16))
+    got = on.serve("generate", toks, max_new_tokens=10)[0]
+    np.testing.assert_array_equal(
+        got, off.serve("generate", toks, max_new_tokens=10)[0])
+
+    _, caches, _ = jax.jit(functools.partial(prefill, cfg, cache_len=40))(
+        params, jnp.asarray(toks))
+    tok, pos = jnp.asarray(got[:, :1]), jnp.asarray([16])
+    routed = jax.jit(functools.partial(decode_step, cfg))(
+        params, tok, pos, caches)[0]
+    _force_capacity(monkeypatch)
+    capacity = jax.jit(functools.partial(decode_step, cfg))(
+        params, tok, pos, caches)[0]
+    np.testing.assert_allclose(np.asarray(routed), np.asarray(capacity),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("program,batch,capacity_factor,mesh,path", [
+    ("decode", 1, None, False, "routed"),
+    ("decode", 1, 1.25, False, "routed"),
+    ("prefill", 1, None, False, "capacity"),
+    ("score", 1, None, False, "capacity"),
+    ("decode", 2, 1.25, False, "capacity"),  # cap 1 < group 2
+    ("decode", 1, None, True, "capacity"),   # a mesh is active
+])
+def test_moe_path_rule(program, batch, capacity_factor, mesh, path):
+    """Only a step that routes few tokens with no drop possible and no
+    mesh reads the routed experts alone; prefill, score, batched decode
+    past capacity and a sharded program keep the capacity dispatch."""
+    import dataclasses
+    from jax.sharding import Mesh
+    from repro.configs import get_reduced
+    from repro.models import layers as L
+    from repro.serving import ServingEngine
+    cfg = get_reduced("granite-moe-1b-a400m")
+    if capacity_factor is not None:
+        cfg = cfg.with_(moe=dataclasses.replace(
+            cfg.moe, capacity_factor=capacity_factor))
+    eng = ServingEngine(cfg, batch_size=batch, prefill_len=16, max_len=24)
+    entry = "score" if program == "score" else "generate"
+    fn, args = eng.entry_programs(entry)[program]
+    with L.moe_paths() as seen:
+        if mesh:
+            with Mesh(np.array(jax.devices()[:1]), ("data",)):
+                fn.lower(*args)
+        else:
+            fn.lower(*args)
+    assert seen == {path}

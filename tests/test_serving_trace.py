@@ -187,6 +187,19 @@ def test_lazy_compile_opens_its_component_span_under_serve():
                                            "new_tokens": 0}
 
 
+@pytest.mark.parametrize("arch,entry,path", [
+    ("granite-moe-1b-a400m", "generate", "routed"),  # its decode step
+    ("granite-moe-1b-a400m", "score", "capacity"),
+    ("granite-8b", "generate", None),
+])
+def test_compile_span_names_the_moe_path(arch, entry, path):
+    configure_tracing(enabled=True)
+    _engine(arch).cold_start()
+    (span,) = [s for s in get_tracer().snapshot()
+               if s.name == f"component:compile.{entry}"]
+    assert span.attrs.get("moe_path") == path
+
+
 # ------------------------------------------ step programs and device scopes
 def test_step_programs_compile_under_their_own_names():
     eng = _engine("granite-moe-1b-a400m")
