@@ -7,6 +7,26 @@ a file of its own that this module finds by the name in
 ``BENCHMARK.json``: ``configs/<config>.json``, ``traffic/<mix>.json``
 (generated by ``traffic/<kind>.py``), ``metrics/<metric>.py`` and
 ``reference/<family>.py``.
+
+A configuration file states the model with its published keys, as
+published, and beside them these keys, which every such file has:
+
+- ``arch`` and ``program_overrides``: the program's configuration
+  (``repro.configs.get_config(arch).with_(**program_overrides)``);
+- ``engine``: the ``ServingEngine`` keyword arguments of every engine;
+- ``check``: the limits and the control of the correctness check
+  (``check.py``);
+- ``reference``: the model family, ``reference/<family>.py``;
+- ``reduced``: the published keys changed to fit the chip;
+- ``assumed``: what the file states that the source does not.
+
+The family module reads the published keys (``check.py`` reads
+``vocab_size`` alone, a key every family's file has).  It provides
+``template``/``weights`` (the served weights rebuilt from a tenant's
+seed), ``forward(params, tokens, m, prompt_len, quant=None)`` (float32
+logits), ``program_sizes`` (for ``matching``) and ``decode_step`` (for
+``metrics/decode_mfu.py``); see ``reference/__init__.py``.  So a model
+of another family is new files only.
 """
 
 from __future__ import annotations
@@ -158,33 +178,30 @@ def program_config(spec: dict):
         get_config(spec["arch"]).with_(**spec["program_overrides"]), spec)
 
 
+class _Absent:
+    """What an attribute path the program's configuration lacks reads."""
+
+    def __repr__(self):
+        return "absent"
+
+
+ABSENT = _Absent()
+
+
+def attribute(obj, path: str):
+    """``obj``'s attribute at the dotted ``path``; ``ABSENT`` where a
+    step of it is missing (as any under ``moe`` without experts)."""
+    for name in path.split("."):
+        obj = getattr(obj, name, ABSENT)
+    return obj
+
+
 def matching(cfg, spec: dict):
-    """``cfg``, if its sizes are those the configuration file states."""
-    want = {
-        "d_model": spec["hidden_size"],
-        "n_heads": spec["num_attention_heads"],
-        "n_kv_heads": spec["num_key_value_heads"],
-        "head_dim": spec["hidden_size"] // spec["num_attention_heads"],
-        "n_layers": spec["num_hidden_layers"],
-        "vocab": spec["vocab_size"],
-        "rope_theta": spec["rope_theta"],
-        "tie_embeddings": spec["tie_word_embeddings"],
-        "dtype": spec["dtype"],
-        "norm_eps": spec["rms_norm_eps"],
-    }
-    have = {k: getattr(cfg, k) for k in want}
-    if cfg.moe is not None:
-        want.update(experts=spec["num_local_experts"],
-                    top_k=spec["num_experts_per_tok"],
-                    ff=spec["intermediate_size"],
-                    capacity_factor=spec["capacity_factor"],
-                    moe_group=spec["moe_group"])
-        have.update(experts=cfg.moe.n_experts, top_k=cfg.moe.top_k,
-                    ff=cfg.moe.d_expert_ff,
-                    capacity_factor=cfg.moe.capacity_factor,
-                    moe_group=cfg.moe_group)
-    else:
-        want["ff"], have["ff"] = spec["intermediate_size"], cfg.d_ff
+    """``cfg``, if its sizes are those the configuration file states:
+    every attribute path the family module's ``program_sizes(spec)``
+    names holds the value it gives."""
+    want = reference_module(spec).program_sizes(spec)
+    have = {path: attribute(cfg, path) for path in want}
     if have != want:
         raise SystemExit(f"bench: the program's {spec['arch']} is not the "
                          f"configuration in the file: {have} != {want}")

@@ -1,11 +1,12 @@
 """The decode step's share of the chip's peak: the least time the step
-needs at the published peaks (``work.decode_step``: the weights one
-token multiplies, its routed experts only, and the keys and values it
-reads, at the mean context of the decode steps served in the traced
-part) over the mean device time of a decode program run."""
+needs at the published peaks (the family module's ``decode_step``: the
+weights one token multiplies, its routed experts only, and the keys and
+values it reads, at the mean context of the decode steps served in the
+traced part) over the mean device time of a decode program run."""
 
 from devtrace import step_programs
-from work import decode_step, least_time
+from harness import reference_module
+from work import least_time
 
 
 def read(run):
@@ -22,5 +23,6 @@ def read(run):
                 for j in range(r["new_tokens"] - 1)]
     if not contexts:
         return None
-    flops, nbytes = decode_step(run.spec, sum(contexts) / len(contexts))
+    flops, nbytes = reference_module(run.spec).decode_step(
+        run.spec, sum(contexts) / len(contexts))
     return least_time(flops, nbytes, run.peaks) / step_s

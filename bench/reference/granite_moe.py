@@ -19,6 +19,10 @@ program computes:
   from it.  Each decoded token is routed alone, which never drops.
   ``forward`` applies the same rule to the first ``prompt_len``
   positions and none to the rest; the published model drops nothing.
+
+``program_sizes`` and ``decode_step`` read the same keys of the
+configuration file for the harness's size check and for the decode
+step's share of the chip's peak.
 """
 
 from __future__ import annotations
@@ -30,6 +34,7 @@ from reference.common import (
     REPLACED, Quant, decoder, ein, gelu_tanh, normal_leaf, seeded_experts,
     seeded_weights,
 )
+from work import BYTES
 
 
 def dims(m):
@@ -113,3 +118,52 @@ def forward(params, tokens, m, prompt_len, quant: Quant = None):
     return decoder(params, tokens, m,
                    lambda p, h: moe(p["moe"], h, m, prompt_len, quant),
                    quant)
+
+
+def program_sizes(m):
+    """The program's ``ArchConfig`` attribute paths and the values the
+    configuration file states for them."""
+    return {
+        "d_model": m["hidden_size"],
+        "n_heads": m["num_attention_heads"],
+        "n_kv_heads": m["num_key_value_heads"],
+        "head_dim": m["hidden_size"] // m["num_attention_heads"],
+        "n_layers": m["num_hidden_layers"],
+        "vocab": m["vocab_size"],
+        "rope_theta": m["rope_theta"],
+        "tie_embeddings": m["tie_word_embeddings"],
+        "dtype": m["dtype"],
+        "norm_eps": m["rms_norm_eps"],
+        "moe.n_experts": m["num_local_experts"],
+        "moe.top_k": m["num_experts_per_tok"],
+        "moe.d_expert_ff": m["intermediate_size"],
+        "moe.capacity_factor": m["capacity_factor"],
+        "moe_group": m["moe_group"],
+    }
+
+
+def decode_step(m: dict, context: float) -> tuple[float, float]:
+    """(FLOPs, bytes) of one decoded token with ``context`` cached
+    positions before it, counted from the configuration's shapes (not
+    from what the program happens to do).
+
+    A decode step of one token at batch 1 needs every weight it
+    multiplies once: attention projections, router, the
+    ``num_experts_per_tok`` experts it is routed to (not all experts),
+    norms, one embedding row and the tied head over the whole
+    vocabulary; and it reads the keys and values of the ``context``
+    positions before it and writes its own."""
+    D, H = m["hidden_size"], m["num_attention_heads"]
+    K, F = m["num_key_value_heads"], m["intermediate_size"]
+    L, V = m["num_hidden_layers"], m["vocab_size"]
+    hd = D // H
+    attn = D * H * hd + 2 * D * K * hd + H * hd * D
+    ff = D * m["num_local_experts"] + \
+        m["num_experts_per_tok"] * (D * 2 * F + F * D)
+    matmul_params = L * (attn + ff) + V * D
+    params = matmul_params + L * 2 * D + D + D   # norms, embedding row
+    width = BYTES[m["dtype"]]
+    kv_read = L * 2 * context * K * hd * width
+    kv_write = L * 2 * K * hd * width
+    flops = 2 * matmul_params + L * 2 * 2 * (context + 1) * H * hd
+    return float(flops), float(params * width + kv_read + kv_write)

@@ -4,14 +4,14 @@ import pytest
 
 import devtrace
 import harness
-import work
+from reference import granite_moe
 from run import Run
 
-SPEC = {"hidden_size": 64, "num_attention_heads": 4,
-        "num_key_value_heads": 2, "intermediate_size": 32,
-        "num_hidden_layers": 2, "vocab_size": 256, "dtype": "bfloat16",
-        "num_local_experts": 8, "num_experts_per_tok": 2,
-        "engine": {"prefill_len": 16}}
+SPEC = {"reference": "granite_moe", "hidden_size": 64,
+        "num_attention_heads": 4, "num_key_value_heads": 2,
+        "intermediate_size": 32, "num_hidden_layers": 2,
+        "vocab_size": 256, "dtype": "bfloat16", "num_local_experts": 8,
+        "num_experts_per_tok": 2, "engine": {"prefill_len": 16}}
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
 
 
@@ -60,7 +60,7 @@ def recorded():
 
 CASES = {
     # latencies 1.0, 0.7, 0.1, 0.3 s: linear p95 at rank 2.85 of 0..3
-    "latency_p95_ms": 955.0,
+    "request_p95_ms": 955.0,
     "warm_ms_per_token": (0.2 + 0.3) / 10 * 1e3,
     "peak_hbm_gb": 15.0,
     "setup_s": 20.5,
@@ -84,7 +84,7 @@ def test_decode_mfu():
     # decode steps of the generate requests started in the traced part:
     # contexts 16..18, 16..18, 16..20 -> mean 17.333...
     ctx = [16 + j for n in (4, 4, 6) for j in range(n - 1)]
-    flops, nbytes = work.decode_step(SPEC, sum(ctx) / len(ctx))
+    flops, nbytes = granite_moe.decode_step(SPEC, sum(ctx) / len(ctx))
     want = max(flops / 1e12, nbytes / 1e9) / 0.02
     got = harness.metric_module("decode_mfu").read(run)
     assert got == pytest.approx(want)
@@ -92,7 +92,7 @@ def test_decode_mfu():
 
 
 def test_decode_step_counts_routed_experts_only():
-    f0, b0 = work.decode_step(SPEC, 0)
+    f0, b0 = granite_moe.decode_step(SPEC, 0)
     D, H, K, F, L, V, E, k = 64, 4, 2, 32, 2, 256, 8, 2
     hd = D // H
     matmul = L * (D * H * hd + 2 * D * K * hd + H * hd * D
@@ -100,7 +100,7 @@ def test_decode_step_counts_routed_experts_only():
     assert f0 == 2 * matmul + L * 2 * 2 * H * hd
     # bf16 weights, norms and one embedding row; this token's K and V
     assert b0 == 2 * (matmul + L * 2 * D + 2 * D) + L * 2 * K * hd * 2
-    f1, b1 = work.decode_step(SPEC, 10)
+    f1, b1 = granite_moe.decode_step(SPEC, 10)
     assert b1 - b0 == L * 2 * 10 * K * hd * 2
 
 

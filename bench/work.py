@@ -1,35 +1,14 @@
-"""Operations and bytes a step needs, counted from a configuration's
-shapes (not from what the program happens to do).
+"""The chip's side of a roofline: bytes per element of each stored type
+and the least time an amount of work needs at the published peaks.
 
-A decode step of one token at batch 1 needs every weight it multiplies
-once: attention projections, router, the ``num_experts_per_tok``
-experts it is routed to (not all experts), norms, one embedding row
-and the tied head over the whole vocabulary; and it reads the keys and
-values of the ``context`` positions before it and writes its own.
+What a step or a kernel of one model family needs, in operations and
+bytes, is counted from its configuration's shapes by that family's
+module (``reference/<family>.py``: ``decode_step``).
 """
 
 from __future__ import annotations
 
 BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
-
-
-def decode_step(m: dict, context: float) -> tuple[float, float]:
-    """(FLOPs, bytes) of one decoded token with ``context`` cached
-    positions before it."""
-    D, H = m["hidden_size"], m["num_attention_heads"]
-    K, F = m["num_key_value_heads"], m["intermediate_size"]
-    L, V = m["num_hidden_layers"], m["vocab_size"]
-    hd = D // H
-    attn = D * H * hd + 2 * D * K * hd + H * hd * D
-    ff = D * m["num_local_experts"] + \
-        m["num_experts_per_tok"] * (D * 2 * F + F * D)
-    matmul_params = L * (attn + ff) + V * D
-    params = matmul_params + L * 2 * D + D + D   # norms, embedding row
-    width = BYTES[m["dtype"]]
-    kv_read = L * 2 * context * K * hd * width
-    kv_write = L * 2 * K * hd * width
-    flops = 2 * matmul_params + L * 2 * 2 * (context + 1) * H * hd
-    return float(flops), float(params * width + kv_read + kv_write)
 
 
 def least_time(flops: float, nbytes: float, peaks: dict) -> float:
